@@ -236,7 +236,7 @@ def mesh_axis(args, gen):
         env["XLA_FLAGS"] = merge_flags(
             os.environ.get("XLA_FLAGS", ""),
             f"--xla_force_host_platform_device_count={n}")
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        env["JAX_PLATFORMS"] = "cpu"    # forced host devices only
         cmd = [sys.executable, os.path.abspath(__file__),
                "--mesh-worker", str(n), "--slots", str(args.slots),
                "--requests", str(args.requests),
@@ -281,6 +281,14 @@ def main() -> None:
     gen = args.gen or (32 if args.smoke else 48)
     capacity = args.prompt_len + gen + 8
     rng = np.random.default_rng(0)
+
+    platform = jax.devices()[0].platform
+    if platform != "cpu":
+        # the mesh axis runs its arms on forced host (CPU) devices in child
+        # processes, and this process now holds the chip: its numbers would
+        # be CPU numbers filed next to chip numbers
+        raise SystemExit(f"serve_bench measures the CPU backend only (its "
+                         f"mesh_axis forces host devices); found {platform}")
 
     if args.mesh_worker:
         _mesh_worker(args, cfg, gen, capacity, rng)

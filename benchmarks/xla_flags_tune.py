@@ -4,17 +4,16 @@ XLA reads ``XLA_FLAGS`` once at backend init, so every (flag set × mesh
 topology) cell runs in a fresh subprocess: the worker builds a mesh-sharded
 ``ServeEngine`` on ``--xla_force_host_platform_device_count=N`` host
 devices, compiles the decode burst, times it, and prints one JSON line.
-The parent sweeps the named flag sets for the current backend, picks the
+The parent never touches JAX; it sweeps the named flag sets, picks the
 winner per topology, and records everything (winner + full per-set
 timings) in a bench artifact:
 
   PYTHONPATH=src python benchmarks/xla_flags_tune.py --smoke --json BENCH_xla_flags.json
 
-Flag sets follow the saxml serving playbook: a BASE set, an MBLO set
-(memory-bound-loop optimizer) and a CM set (windowed-einsum /
-async-collective-permute communication/compute overlap) on TPU; on CPU the
-sweep covers the documented cpu-backend levers (fast-math, thunk runtime,
-concurrency-optimized scheduler) so the harness exercises end to end in CI.
+The sweep covers the documented cpu-backend levers (fast-math, thunk
+runtime, concurrency-optimized scheduler).  Forced host devices exist only
+on the CPU backend, so a worker that finds any other platform stops with an
+error instead of timing something else.
 ``append_xla_flags`` semantics: a flag the user already set in the
 environment is never overridden by a set below.
 """
@@ -31,35 +30,13 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro.common.xla_env import merge_flags, render_flags  # noqa: E402
 
-# named flag sets per backend.  TPU sets are from the saxml serving recipe;
-# CPU sets cover that backend's documented performance levers.
+# named flag sets: the cpu backend's documented performance levers.  Every
+# cell runs on forced host devices, so there is no chip to tune here.
 FLAG_SETS = {
-    "tpu": {
-        "BASE": {
-            "xla_tpu_enable_data_parallel_all_reduce_opt": True,
-            "xla_tpu_data_parallel_opt_different_sized_ops": True,
-            "xla_tpu_enable_async_collective_fusion": True,
-            "xla_tpu_enable_async_collective_fusion_fuse_all_gather": True,
-            "xla_tpu_enable_async_collective_fusion_multiple_steps": True,
-            "xla_tpu_overlap_compute_collective_tc": True,
-            "xla_enable_async_all_gather": True,
-        },
-        "MBLO": {
-            "xla_tpu_enforce_prefetch_fifo_order": True,
-            "xla_tpu_memory_bound_loop_optimizer_options": "enabled:true",
-        },
-        "CM": {
-            "xla_jf_spmd_threshold_for_windowed_einsum_mib": 0,
-            "xla_enable_async_collective_permute": True,
-            "xla_tpu_spmd_unroll_windowed_einsum": True,
-        },
-    },
-    "cpu": {
-        "BASE": {},
-        "FASTMATH": {"xla_cpu_enable_fast_math": True},
-        "NOTHUNKS": {"xla_cpu_use_thunk_runtime": False},
-        "CONCSCHED": {"xla_cpu_enable_concurrency_optimized_scheduler": True},
-    },
+    "BASE": {},
+    "FASTMATH": {"xla_cpu_enable_fast_math": True},
+    "NOTHUNKS": {"xla_cpu_use_thunk_runtime": False},
+    "CONCSCHED": {"xla_cpu_enable_concurrency_optimized_scheduler": True},
 }
 # non-BASE sets apply ON TOP of BASE (saxml composes them the same way)
 _COMPOSE_WITH_BASE = True
@@ -70,6 +47,12 @@ BURST = 8
 def _worker(args) -> int:
     """One measurement cell; env (XLA_FLAGS) was fixed by the parent."""
     import jax
+
+    platform = jax.devices()[0].platform
+    if platform != "cpu":
+        print(f"xla_flags_tune forces host devices and measures the CPU "
+              f"backend only; this worker found {platform}", file=sys.stderr)
+        return 2
 
     from repro.common.config import ModelConfig
     from repro.models import transformer as T
@@ -116,7 +99,6 @@ def _run_cell(set_name: str, flags: dict, mesh: int, args) -> dict:
         os.environ.get("XLA_FLAGS", ""),
         f"--xla_force_host_platform_device_count={mesh}",
         *render_flags(flags).split())
-    env.setdefault("JAX_PLATFORMS", "cpu")
     cmd = [sys.executable, os.path.abspath(__file__), "--worker",
            "--mesh", str(mesh), "--iters", str(args.iters)]
     proc = subprocess.run(cmd, env=env, capture_output=True, text=True,
@@ -134,23 +116,17 @@ def main() -> int:
     ap.add_argument("--iters", type=int, default=5)
     ap.add_argument("--smoke", action="store_true",
                     help="topologies {1,2} instead of {1,2,4,8}")
-    ap.add_argument("--backend", default="",
-                    help="flag-set family (default: detect, cpu off-TPU)")
     ap.add_argument("--json", default="", help="write the report here")
     args = ap.parse_args()
 
     if args.worker:
         return _worker(args)
 
-    backend = args.backend
-    if not backend:
-        backend = "tpu" if os.environ.get("JAX_PLATFORMS", "") == "tpu" \
-            else "cpu"
-    sets = FLAG_SETS[backend]
+    sets = FLAG_SETS
     base = sets.get("BASE", {})
     topologies = (1, 2) if args.smoke else (1, 2, 4, 8)
 
-    report = {"suite": "xla_flags", "backend": backend,
+    report = {"suite": "xla_flags", "backend": "cpu",
               "burst": BURST,
               "flag_sets": {k: render_flags(v) for k, v in sets.items()},
               "topologies": {}}

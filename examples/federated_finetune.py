@@ -34,6 +34,7 @@ import argparse
 import os
 import time
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.common.config import FedConfig, LoRAConfig, ModelConfig, OptimConfig
 import repro.core.distributed  # noqa: F401  (registers florist_sharded)
 from repro.core.aggregators import available_aggregators
@@ -57,6 +58,7 @@ PROFILES = {
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--method", default="florist",
                     choices=available_aggregators())

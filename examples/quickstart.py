@@ -9,12 +9,14 @@ inspect the chosen ranks and the communication savings.
 import jax
 import jax.numpy as jnp
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.common.config import FedConfig, LoRAConfig, ModelConfig, OptimConfig
 from repro.core import costs as C
 from repro.core.federated import FederatedTrainer
 
 
 def main():
+    enable_compile_cache()
     cfg = ModelConfig(name="quickstart-tiny", family="dense", num_layers=2,
                       d_model=64, num_heads=4, num_kv_heads=2, head_dim=16,
                       d_ff=128, vocab_size=256, dtype="float32")

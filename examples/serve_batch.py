@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.configs import get_smoke_config, lora_targets
 from repro.models import transformer as T
 from repro.peft.lora import init_lora
@@ -24,6 +25,7 @@ from repro.train.step import make_serve_step
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-0.5b")
     ap.add_argument("--batch", type=int, default=4)

@@ -26,6 +26,7 @@ import argparse
 
 import numpy as np
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.common.config import FedConfig, LoRAConfig, ModelConfig, OptimConfig
 from repro.core.federated import FederatedTrainer
 from repro.serve.adapters import AdapterRegistry
@@ -33,6 +34,7 @@ from repro.serve.engine import SamplingParams, ServeEngine
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--rounds", type=int, default=2)
     ap.add_argument("--requests-per-round", type=int, default=2)

@@ -5,6 +5,7 @@ eval quality vs τ, on the synthetic federated task.
 """
 import argparse
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.common.config import FedConfig, LoRAConfig, ModelConfig, OptimConfig
 from repro.core.aggregators import make_aggregator
 from repro.core.federated import FederatedTrainer
@@ -15,6 +16,7 @@ CFG = ModelConfig(name="sweep-tiny", family="dense", num_layers=4, d_model=64,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--rounds", type=int, default=6)
     ap.add_argument("--taus", default="0.6,0.8,0.9,0.95,0.99")

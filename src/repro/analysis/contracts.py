@@ -167,7 +167,7 @@ def _trace(name: str, case: Case, cc: ContractCase):
     if hit is not None:
         return hit
     out = jax.eval_shape(cc.fn, *cc.args)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         closed = jax.make_jaxpr(cc.fn)(*cc.args)
     bans = jaxpr_violations(closed, forbid_f64=cc.forbid_f64,
                             forbid_callbacks=cc.forbid_callbacks)

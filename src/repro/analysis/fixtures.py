@@ -54,13 +54,13 @@ def chunk_width(cfg: ModelConfig) -> int:
 def abstract_mesh(model: int) -> AbstractMesh:
     """A device-free serve-shaped mesh: pspec rules only read axis sizes,
     so divisibility validates at any mesh width on a 1-device host."""
-    return AbstractMesh((("data", 1), ("model", model)))
+    return AbstractMesh((1, model), ("data", "model"))
 
 
 def abstract_fed_mesh(data: int) -> AbstractMesh:
     """A device-free fed-shaped mesh (data=N, model=1): the client-parallel
     cohort specs validate at any data width on a 1-device host."""
-    return AbstractMesh((("data", data), ("model", 1)))
+    return AbstractMesh((data, 1), ("data", "model"))
 
 
 def abstract_params(cfg: ModelConfig):

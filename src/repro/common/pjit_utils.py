@@ -46,10 +46,7 @@ class active_mesh:
 def _ambient_mesh():
     if _ACTIVE_MESH is not None:
         return _ACTIVE_MESH
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-    except Exception:
-        return None
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or not getattr(mesh, "axis_names", ()):
         return None
     return mesh
@@ -92,11 +89,7 @@ def constrain(x, spec: Sequence[Axis]):
             continue
         fixed.append(axis if dim % _axis_size(sizes, axis) == 0 else None)
     fixed += [None] * (x.ndim - len(fixed))
-    try:
-        return jax.lax.with_sharding_constraint(
-            x, NamedSharding(mesh, P(*fixed)))
-    except Exception:
-        return x
+    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, P(*fixed)))
 
 
 def batch_axes() -> Axis:
@@ -109,14 +102,7 @@ def batch_axes() -> Axis:
 
 
 def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = False):
-    """Version-portable ``shard_map``: jax >= 0.5 exposes ``jax.shard_map``
-    with ``check_vma``; 0.4.x only has the experimental one with
-    ``check_rep`` (same semantics: replication/varying-manual-axes check)."""
-    try:
-        sm = jax.shard_map
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map as esm
-        return esm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=check_vma)
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_vma=check_vma)
+    """``jax.shard_map``, with its varying-manual-axes check (``check_vma``)
+    off unless the caller asks for it."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)

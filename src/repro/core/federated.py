@@ -59,6 +59,7 @@ from repro.core.runtime import (ClientRunner, DeadClientError, RankPolicy,
 from repro.data.synthetic import ClientDataset, make_eval_data, make_federated_data
 from repro.models import transformer as T
 from repro.peft.lora import init_lora, merge_lora
+from repro.train.loss import bounded_loss_chunk
 from repro.train.step import make_eval_step, make_train_step
 
 
@@ -167,7 +168,9 @@ class FederatedTrainer:
         ev = eval_data if eval_data is not None else make_eval_data(
             seq_len=seq_len, vocab=cfg.vocab_size)
         self.eval_batch = {k: jnp.asarray(v) for k, v in ev.items()}
-        self._eval = _cached_eval_step(cfg, seq_len)
+        rows, ev_len = self.eval_batch["tokens"].shape
+        self._eval = _cached_eval_step(
+            cfg, bounded_loss_chunk(rows, ev_len, cfg.vocab_size))
         self.history: List[RoundRecord] = []
         self._pending_resumes = 0    # stamped into the first post-resume record
 

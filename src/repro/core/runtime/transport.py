@@ -54,17 +54,14 @@ import zlib
 from typing import Any, Dict, List, Optional, Sequence, Tuple, Type
 
 import jax
+import ml_dtypes
 import numpy as np
 
 from repro.core.aggregators.base import (adapter_leaf_paths,
                                          default_wire_arrays, get_path,
                                          set_path)
 
-try:  # ships with jax
-    import ml_dtypes
-    _BF16 = np.dtype(ml_dtypes.bfloat16)
-except ImportError:  # pragma: no cover - jax always depends on ml_dtypes
-    _BF16 = None
+_BF16 = np.dtype(ml_dtypes.bfloat16)
 
 #: rank axis of each wire tensor (A: rows are rank, B: columns are rank)
 _RANK_AXIS = {"A": -2, "B": -1}
@@ -177,8 +174,6 @@ class Bf16Codec(Codec):
     bytes_per_param = 2.0
 
     def encode(self, arr) -> EncodedArray:
-        if _BF16 is None:
-            raise RuntimeError("bf16 codec requires ml_dtypes")
         a = np.asarray(arr, np.float32).astype(_BF16)
         return EncodedArray(a.tobytes(), a.shape)
 

@@ -30,13 +30,14 @@ from repro.kernels.ring_decode import (NEG_INF, flush_flash_scratch,
 
 
 def _kernel(*refs, scale: float, bk: int, nk: int, cap: int, window: int,
-            quantized: bool):
+            quantized: bool, n_heads: int):
     if quantized:
         (pos_ref, len_ref, n_ref, q_ref, ckv_ref, kr_ref, s1_ref, s2_ref,
          o_ref, m_scr, l_scr, acc_scr) = refs
     else:
         (pos_ref, len_ref, n_ref, q_ref, ckv_ref, kr_ref,
          o_ref, m_scr, l_scr, acc_scr) = refs
+    b = pl.program_id(0) // n_heads
     ik = pl.program_id(1)
 
     @pl.when(ik == 0)
@@ -52,7 +53,7 @@ def _kernel(*refs, scale: float, bk: int, nk: int, cap: int, window: int,
     k = jnp.concatenate([ckv, kr], axis=-1)           # (bk, kvr + rope)
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale  # (C, bk)
 
-    mask = ring_mask_tile(pos_ref[0, 0], len_ref[0, 0], n_ref[0, 0], ik,
+    mask = ring_mask_tile(pos_ref[b], len_ref[b], n_ref[b], ik,
                           bk=bk, cap=cap, C=q.shape[0], window=window)
     s = jnp.where(mask, s, NEG_INF)
     online_softmax_step(s, ckv, m_scr, l_scr, acc_scr)  # value = latent
@@ -82,41 +83,41 @@ def mla_ring_decode_kernel(q_eff, c_kv, k_rope, pos, length, n_tokens,
     quantized = c_kv_scale is not None
 
     qf = q_eff.transpose(0, 2, 1, 3).reshape(B * H, C, dq)
-    scal = [x.astype(jnp.int32).reshape(B, 1)
-            for x in (pos, length, n_tokens)]
+    # (B,) ring scalars through scalar prefetch (see ring_decode)
+    scal = [x.astype(jnp.int32) for x in (pos, length, n_tokens)]
 
-    def row_index(bh, ik_):
-        return (bh // H, 0)
-
-    def q_index(bh, ik_):
+    def q_index(bh, ik_, *_):
         return (bh, 0, 0)
 
-    def kv_index(bh, ik_):
+    def kv_index(bh, ik_, *_):
         return (bh // H, ik_, 0)
 
-    scalar_spec = pl.BlockSpec((1, 1), row_index, memory_space=pltpu.SMEM)
-    in_specs = [scalar_spec] * 3 + [
+    in_specs = [
         pl.BlockSpec((1, C, dq), q_index),
         pl.BlockSpec((1, bk, kvr), kv_index),
         pl.BlockSpec((1, bk, dq - kvr), kv_index),
     ]
-    args = scal + [qf, c_kv, k_rope]
+    args = [qf, c_kv, k_rope]
     if quantized:
         in_specs += [pl.BlockSpec((1, bk, 1), kv_index)] * 2
         args += [c_kv_scale, k_rope_scale]
 
-    out = pl.pallas_call(
-        functools.partial(_kernel, scale=scale, bk=bk, nk=nk, cap=cap,
-                          window=window, quantized=quantized),
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
         grid=(B * H, nk),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, C, kvr), q_index),
-        out_shape=jax.ShapeDtypeStruct((B * H, C, kvr), jnp.float32),
         scratch_shapes=[
             pltpu.VMEM((C, 1), jnp.float32),
             pltpu.VMEM((C, 1), jnp.float32),
             pltpu.VMEM((C, kvr), jnp.float32),
         ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_kernel, scale=scale, bk=bk, nk=nk, cap=cap,
+                          window=window, quantized=quantized, n_heads=H),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B * H, C, kvr), jnp.float32),
         interpret=interpret,
-    )(*args)
+    )(*scal, *args)
     return out.reshape(B, H, C, kvr).transpose(0, 2, 1, 3)

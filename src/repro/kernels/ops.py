@@ -1,9 +1,11 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On CPU (this container) the kernels execute in ``interpret=True`` mode —
-the kernel body runs as traced Python, validating the exact TPU code path.
-Shape padding to block multiples is handled here so callers can use
-arbitrary sizes.  ``lora_matmul`` carries a ``custom_vjp`` (backward via the
+On a TPU backend the kernels compile natively.  On any other backend they
+run in Pallas ``interpret=True`` mode, which is a rule for the CPU tests
+only: it checks a kernel's math, not whether the TPU compiler accepts its
+blocks (``tests/test_tpu_compile.py`` does that), and its timings say
+nothing about the chip.  Shape padding to block multiples is handled here
+so callers can use arbitrary sizes.  ``lora_matmul`` carries a ``custom_vjp`` (backward via the
 reference math) so ``use_kernels=True`` training differentiates through the
 fused forward.
 """
@@ -215,8 +217,14 @@ def bgmv(x, a_pages, b_pages, table, rank, scale, ids):
 
 
 def wkv6(r, k, v, w, u, chunk: int = 256):
-    return wkv6_kernel(r, k, v, w, u, chunk=min(chunk, r.shape[1]),
-                       interpret=_interpret())
+    """RWKV6 recurrence at any sequence length.  S is padded to the chunk
+    (itself a multiple of the kernel's 16-row tile) with k = v = w = 0:
+    the state passes through padded steps unchanged, and their outputs are
+    sliced off."""
+    S = r.shape[1]
+    chunk = min(chunk, -(-S // 16) * 16)
+    pads = [_pad_to(t, 1, chunk)[0] for t in (r, k, v, w)]
+    return wkv6_kernel(*pads, u, chunk=chunk, interpret=_interpret())[:, :S]
 
 
 def adapter_gram(x, bm: int = 512):

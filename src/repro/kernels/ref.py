@@ -116,3 +116,21 @@ def adapter_gram_ref(x):
     """Gram matrix xᵀ x in fp32. x: (m, r)."""
     xf = x.astype(jnp.float32)
     return xf.T @ xf
+
+
+def bgmv_ref(x, a_pages, b_pages, table, rank, scale, ids):
+    """Multi-tenant paged LoRA delta oracle: row b applies adapter
+    ``ids[b]`` — its pages ``table[ids[b]]``, lanes below its rank, its
+    scale.  x: (B,C,din); a_pages: (P,pr,din); b_pages: (P,dout,pr).
+    Page slot j's lane ℓ is global lane j·pr + ℓ.  Returns (B,C,dout) fp32."""
+    B = x.shape[0]
+    tbl = table[ids]                                       # (B, Pmax)
+    A = a_pages[tbl].astype(jnp.float32)                   # (B,Pmax,pr,din)
+    A = A.reshape(B, -1, A.shape[-1])                      # (B, R, din)
+    Bp = jnp.moveaxis(b_pages[tbl].astype(jnp.float32), 2, 1)
+    Bp = Bp.reshape(B, Bp.shape[1], -1)                    # (B, dout, R)
+    keep = jnp.arange(A.shape[1])[None, :] < rank[ids][:, None]
+    z = jnp.einsum("bcd,brd->bcr", x.astype(jnp.float32), A)
+    z = jnp.where(keep[:, None, :], z, 0.0)
+    return (jnp.einsum("bcr,bor->bco", z, Bp)
+            * scale.astype(jnp.float32)[ids][:, None, None])

@@ -80,13 +80,14 @@ def flush_flash_scratch(o_ref, m_scr, l_scr, acc_scr):
 
 
 def _kernel(*refs, scale: float, bk: int, nk: int, cap: int, window: int,
-            quantized: bool):
+            quantized: bool, n_heads: int):
     if quantized:
         (pos_ref, len_ref, n_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
          o_ref, m_scr, l_scr, acc_scr) = refs
     else:
         (pos_ref, len_ref, n_ref, q_ref, k_ref, v_ref,
          o_ref, m_scr, l_scr, acc_scr) = refs
+    b = pl.program_id(0) // n_heads
     ik = pl.program_id(1)
 
     @pl.when(ik == 0)
@@ -101,7 +102,7 @@ def _kernel(*refs, scale: float, bk: int, nk: int, cap: int, window: int,
         v = v * vs_ref[0]
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale  # (C, bk)
 
-    mask = ring_mask_tile(pos_ref[0, 0], len_ref[0, 0], n_ref[0, 0], ik,
+    mask = ring_mask_tile(pos_ref[b], len_ref[b], n_ref[b], ik,
                           bk=bk, cap=cap, C=q.shape[0], window=window)
     s = jnp.where(mask, s, NEG_INF)
     online_softmax_step(s, v, m_scr, l_scr, acc_scr)
@@ -130,42 +131,43 @@ def ring_decode_kernel(q, k, v, pos, length, n_tokens, cap: int,
     qf = q.transpose(0, 2, 1, 3).reshape(B * H, C, hd)
     kf = k.transpose(0, 2, 1, 3).reshape(B * K, capp, hd)
     vf = v.transpose(0, 2, 1, 3).reshape(B * K, capp, hd)
-    scal = [x.astype(jnp.int32).reshape(B, 1)
-            for x in (pos, length, n_tokens)]
+    # the (B,) ring scalars ride in SMEM via scalar prefetch: a (1,) block
+    # of a (B,) array is not a legal VMEM/SMEM tile on the chip
+    scal = [x.astype(jnp.int32) for x in (pos, length, n_tokens)]
 
-    def row_index(bh, ik_):
-        return (bh // H, 0)
-
-    def q_index(bh, ik_):
+    def q_index(bh, ik_, *_):
         return (bh, 0, 0)
 
-    def kv_index(bh, ik_):
+    def kv_index(bh, ik_, *_):
         return (bh // H * K + (bh % H) // g, ik_, 0)
 
-    scalar_spec = pl.BlockSpec((1, 1), row_index, memory_space=pltpu.SMEM)
-    in_specs = [scalar_spec] * 3 + [
+    in_specs = [
         pl.BlockSpec((1, C, hd), q_index),
         pl.BlockSpec((1, bk, hd), kv_index),
         pl.BlockSpec((1, bk, hd), kv_index),
     ]
-    args = scal + [qf, kf, vf]
+    args = [qf, kf, vf]
     if quantized:
         in_specs += [pl.BlockSpec((1, bk, 1), kv_index)] * 2
         args += [k_scale.transpose(0, 2, 1, 3).reshape(B * K, capp, 1),
                  v_scale.transpose(0, 2, 1, 3).reshape(B * K, capp, 1)]
 
-    out = pl.pallas_call(
-        functools.partial(_kernel, scale=scale, bk=bk, nk=nk, cap=cap,
-                          window=window, quantized=quantized),
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
         grid=(B * H, nk),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, C, hd), q_index),
-        out_shape=jax.ShapeDtypeStruct((B * H, C, hd), jnp.float32),
         scratch_shapes=[
             pltpu.VMEM((C, 1), jnp.float32),    # running max
             pltpu.VMEM((C, 1), jnp.float32),    # running normalizer
             pltpu.VMEM((C, hd), jnp.float32),   # output accumulator
         ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_kernel, scale=scale, bk=bk, nk=nk, cap=cap,
+                          window=window, quantized=quantized, n_heads=H),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B * H, C, hd), jnp.float32),
         interpret=interpret,
-    )(*args)
+    )(*scal, *args)
     return out.reshape(B, H, C, hd).transpose(0, 2, 1, 3)

@@ -29,6 +29,7 @@ from __future__ import annotations
 import argparse
 import json
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.common.config import FedConfig, LoRAConfig, ModelConfig, OptimConfig
 from repro.core.aggregators import available_aggregators
 from repro.core.federated import FederatedTrainer
@@ -39,6 +40,7 @@ from repro.core.runtime import (CRASH_POINTS, FaultPlan, SampledScheduler,
 
 
 def main(argv=None):
+    enable_compile_cache()
     # importing repro.core.distributed registers the sharded backend too
     import repro.core.distributed  # noqa: F401
 

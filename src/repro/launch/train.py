@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint.io import save
+from repro.common.compile_cache import enable_compile_cache
 from repro.common.config import LoRAConfig, OptimConfig
 from repro.configs import get_config, get_smoke_config, lora_targets
 from repro.data.synthetic import make_eval_data
@@ -24,6 +25,7 @@ from repro.train.step import make_eval_step, make_train_step
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true",

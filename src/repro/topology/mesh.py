@@ -15,19 +15,28 @@ from __future__ import annotations
 from typing import Optional, Sequence
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+
+def _auto_mesh(shape, axes, devices=None) -> Mesh:
+    """``jax.make_mesh`` with Auto axes: the partition rules here pin
+    layouts with sharding constraints and leave the rest to the compiler's
+    propagation.  (``jax.make_mesh`` defaults to Explicit axes, under which
+    every gather and scatter on a sharded array needs an out_sharding.)"""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1) -> Mesh:
     """Tiny mesh over however many real devices exist (tests / examples)."""
     n = len(jax.devices())
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return _auto_mesh((n // model, model), ("data", "model"))
 
 
 def make_serve_mesh(model: int = 0, *, devices: Optional[Sequence] = None) -> Mesh:
@@ -40,7 +49,7 @@ def make_serve_mesh(model: int = 0, *, devices: Optional[Sequence] = None) -> Me
     n = model or len(devs)
     if n > len(devs):
         raise ValueError(f"requested model={n} but only {len(devs)} devices")
-    return jax.make_mesh((1, n), ("data", "model"), devices=devs[:n])
+    return _auto_mesh((1, n), ("data", "model"), devices=devs[:n])
 
 
 def make_fed_mesh(data: int = 0, *, devices: Optional[Sequence] = None) -> Mesh:
@@ -55,7 +64,7 @@ def make_fed_mesh(data: int = 0, *, devices: Optional[Sequence] = None) -> Mesh:
     n = data or len(devs)
     if n > len(devs):
         raise ValueError(f"requested data={n} but only {len(devs)} devices")
-    return jax.make_mesh((n, 1), ("data", "model"), devices=devs[:n])
+    return _auto_mesh((n, 1), ("data", "model"), devices=devs[:n])
 
 
 def data_axes(mesh: Mesh):

@@ -13,6 +13,18 @@ import jax.numpy as jnp
 from repro.common.config import ModelConfig
 from repro.models import transformer as T
 
+#: fp32 logits one loss chunk may hold (rows × chunk × vocab × 4 bytes)
+LOGITS_BUDGET_BYTES = 512 * 2**20
+
+
+def bounded_loss_chunk(rows: int, seq_len: int, vocab: int,
+                       budget: int = LOGITS_BUDGET_BYTES) -> int:
+    """The longest sequence chunk, at most ``seq_len``, whose fp32 logits
+    for ``rows`` sequences fit ``budget`` bytes.  A 128-row eval batch at a
+    152k vocabulary costs 78 MB of logits per position, so one chunk of a
+    whole 512-token sequence would need 40 GB."""
+    return max(1, min(seq_len, budget // (4 * rows * vocab)))
+
 
 def _ce_chunk(head: jnp.ndarray, hidden, targets, mask):
     """hidden: (B,c,d), targets: (B,c), mask: (B,c). Returns (sum_loss, sum_cnt, sum_correct)."""

@@ -166,7 +166,7 @@ def test_clean_jaxpr_has_no_violations():
     def fine(x):
         return jnp.sin(x) * 2.0
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         closed = jax.make_jaxpr(fine)(FX.sds((4,), "float32"))
     assert jaxpr_violations(closed) == []
 
@@ -178,7 +178,7 @@ def test_f64_ban_sees_through_nesting():
             return c, v.astype(jnp.float64)
         return jax.lax.scan(body, 0.0, x)[1]
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         closed = jax.make_jaxpr(bad)(FX.sds((4,), "float32"))
     assert any("float64" in v for v in jaxpr_violations(closed))
 

@@ -84,3 +84,32 @@ def test_deterministic_given_seed():
     h1, _ = _run("florist", rounds=2)
     h2, _ = _run("florist", rounds=2)
     assert h1[-1].eval_loss == pytest.approx(h2[-1].eval_loss, abs=1e-6)
+
+
+def test_bounded_eval_chunk_matches_single_chunk():
+    """The trainer's eval chunk keeps rows × chunk × vocab fp32 logits under
+    a byte budget; cut into several chunks (with a ragged tail), the loss is
+    the single-chunk loss up to fp32 summation order."""
+    import jax
+
+    from repro.data.synthetic import make_eval_data
+    from repro.models import transformer as T
+    from repro.train.loss import LOGITS_BUDGET_BYTES, bounded_loss_chunk
+    from repro.train.step import make_eval_step
+
+    rows, seq, vocab = 16, 32, CFG.vocab_size
+    ev = {k: jnp.asarray(v) for k, v in make_eval_data(
+        num_samples=rows, seq_len=seq, vocab=vocab).items()}
+    params = T.init(CFG, jax.random.PRNGKey(0))
+    chunk = bounded_loss_chunk(rows, seq, vocab, budget=5 * rows * vocab * 4)
+    assert chunk == 5 and (seq - 1) % chunk
+    whole = make_eval_step(CFG, loss_chunk=seq)(params, None, ev)
+    cut = make_eval_step(CFG, loss_chunk=chunk)(params, None, ev)
+    np.testing.assert_allclose(float(cut["loss"]), float(whole["loss"]),
+                               rtol=1e-6)
+    assert float(cut["accuracy"]) == float(whole["accuracy"])
+    # small vocabularies keep the old single chunk; the published 152k
+    # vocabulary at 128 × 512 is cut to fit the budget
+    assert bounded_loss_chunk(128, 64, 512) == 64
+    big = bounded_loss_chunk(128, 512, 151_936)
+    assert big < 512 and 128 * big * 151_936 * 4 <= LOGITS_BUDGET_BYTES

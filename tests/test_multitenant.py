@@ -2,7 +2,7 @@
 the jitted hot loop, bgmv kernel parity, and the zero-retrace / hot-swap /
 bit-identity invariants of ``repro.serve.adapters``."""
 import jax
-import jax.core as jcore
+import jax.extend.core as jcore
 import jax.numpy as jnp
 import numpy as np
 import pytest

@@ -210,7 +210,7 @@ def test_cache_leaf_ranks_single_table():
 
 
 def test_shard_map_single_definition():
-    """The version-portable shard_map wrapper has ONE definition; every
+    """The shard_map wrapper has ONE definition; every
     consumer (federated aggregation + model layers + serve decode) binds
     the same object."""
     from repro.common import pjit_utils

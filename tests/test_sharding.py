@@ -90,9 +90,9 @@ class TestDistributedAggregation:
             pytest.skip("single device")
         from repro.core.distributed import make_sharded_florist
         from repro.core.svd import florist_core_padded
+        from repro.topology import make_serve_mesh
         ndev = min(len(jax.devices()), 8)
-        mesh = jax.make_mesh((1, ndev), ("data", "model"),
-                             devices=jax.devices()[:ndev])
+        mesh = make_serve_mesh(ndev)
         L, m, n, r = 8, 32, 24, 12
         B = jnp.asarray(rng.normal(size=(L, m, r)), jnp.float32)
         A = jnp.asarray(rng.normal(size=(L, r, n)), jnp.float32)
