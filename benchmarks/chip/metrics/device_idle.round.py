@@ -1,0 +1,9 @@
+"""Device, round cells: the share of the traced window in which no
+operation ran on the device.  Moves ``round_s``."""
+
+
+def read(ctx):
+    tr = ctx["trace"]
+    if not tr.devices or tr.window_s <= 0 or tr.busy_s <= 0:
+        return None
+    return (1.0 - tr.busy_s / tr.window_s) * 100.0
