@@ -39,6 +39,7 @@ import time
 
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent / "src"))
 
+from repro.common import telemetry  # noqa: E402
 from repro.common.compile_cache import enable_compile_cache  # noqa: E402
 
 import jax  # noqa: E402
@@ -63,7 +64,6 @@ from repro.train.step import make_serve_step  # noqa: E402
 
 MODEL = "qwen2-0.5b"
 TAU = 0.9
-BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 # Tolerances, each on max|got - want| / max|want| against a float32 oracle
 # computed at "highest" matmul precision from the same inputs.
@@ -93,29 +93,24 @@ TOL_SHARDED = 2e-2
 
 
 class PhaseLog:
-    """Wall time, backend-compile seconds and device memory peak per phase,
-    printed as bring-up facts."""
-
-    def __init__(self):
-        self.compile_secs = 0.0
-        jax.monitoring.register_event_duration_secs_listener(self._on_event)
-
-    def _on_event(self, event, secs, **_):
-        if event == BACKEND_COMPILE_EVENT:
-            self.compile_secs += secs
+    """Wall time, compiles and device memory peak per phase, printed as
+    bring-up facts.  Each phase is a span; its compiles are what the
+    telemetry counter charged to it and to the spans inside it."""
 
     @contextlib.contextmanager
     def phase(self, name: str):
         print(f"== phase: {name}", flush=True)
-        t0, c0 = time.perf_counter(), self.compile_secs
-        yield
+        with telemetry.span("smoke." + name) as sp:
+            yield
+        compiled = telemetry.compiles(sp.start, sp.end).values()
         peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
                  for d in jax.devices()]
         peak = ", ".join("n/a" if p is None else f"{p / 2**30:.2f} GiB"
                          for p in peaks)
-        print(f"[bring-up] {name}: {time.perf_counter() - t0:.1f} s wall, "
-              f"{self.compile_secs - c0:.1f} s backend compile, "
-              f"peak_bytes_in_use per device [{peak}]", flush=True)
+        print(f"[bring-up] {name}: {sp.seconds:.1f} s wall, "
+              f"{sum(n for n, _ in compiled)} executables compiled in "
+              f"{sum(s for _, s in compiled):.1f} s (tracing, lowering, "
+              f"backend), peak_bytes_in_use per device [{peak}]", flush=True)
 
 
 def rel_err(got, want) -> float:

@@ -39,6 +39,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.common import telemetry
+
 # ---------------------------------------------------------------------------
 # adapter-tree plumbing (shared by all methods and by costs.py)
 # ---------------------------------------------------------------------------
@@ -238,7 +240,8 @@ class Aggregator:
         """Produce the round's :class:`AggResult` from the accumulators."""
         if self.num_clients == 0:
             raise ValueError(f"{self.name}: finalize() before any add_client()")
-        return self._finalize()
+        with telemetry.span("finalize"):
+            return self._finalize()
 
     # -- subclass hooks ------------------------------------------------------
     def _accumulate(self, update: Dict, weight: float, rank: int) -> None:

@@ -10,6 +10,7 @@ import numpy as np
 
 import jax
 
+from repro.common import telemetry
 from repro.core.aggregators.base import (AggResult, Aggregator,
                                          adapter_leaf_paths, bucket_by_shape,
                                          fold_scale, get_path,
@@ -154,23 +155,33 @@ class FloristAggregator(Aggregator):
         out: Dict = {}
         rank_rec: Dict[Tuple, List[int]] = {}
         spectra: Dict[Tuple, List[np.ndarray]] = {}
-        host = jax.device_get({p: (v[2], v[3]) for p, v in device.items()})
-        for path, (Bg, Ag, _, _) in device.items():
-            sp_h, p_h = host[path]
-            ps = [int(x) for x in p_h]
-            p_max = max(ps)
-            Bg, Ag = Bg[:, :, :p_max], Ag[:, :p_max, :]
-            if not self._state[path]["stacked"]:
-                Bg, Ag = Bg[0], Ag[0]
-            set_path(out, path, {"A": Ag, "B": Bg,
-                                 "scale": self._ref_scales[path]})
-            rank_rec[path] = ps
-            spectra[path] = [np.asarray(s) for s in sp_h]
+        with telemetry.span("finalize.wait"):
+            host = jax.device_get({p: (v[2], v[3])
+                                   for p, v in device.items()})
+        with telemetry.span("finalize.build"):
+            for path, (Bg, Ag, _, _) in device.items():
+                sp_h, p_h = host[path]
+                ps = [int(x) for x in p_h]
+                p_max = max(ps)
+                Bg, Ag = Bg[:, :, :p_max], Ag[:, :p_max, :]
+                if not self._state[path]["stacked"]:
+                    Bg, Ag = Bg[0], Ag[0]
+                set_path(out, path, {"A": Ag, "B": Bg,
+                                     "scale": self._ref_scales[path]})
+                rank_rec[path] = ps
+                spectra[path] = [np.asarray(s) for s in sp_h]
         return AggResult(self.name, out, None, rank_rec, spectra)
 
     def _finalize(self) -> AggResult:
         if self.pipeline == "loop":
             return self._finalize_loop()
+        with telemetry.span("finalize.core"):
+            device = self._dispatch_cores()
+        return self._materialize(device)
+
+    def _dispatch_cores(self) -> Dict[Tuple, Tuple]:
+        """Settle the accumulators and dispatch the batched cores; returns
+        {path: (B_g, A_g, spectra, ranks)} still on the device."""
         inter = self._settle()
         stacks = {p: v[1:] for p, v in inter.items() if v[0] == "stack"}
         deltas = {p: v[1:] for p, v in inter.items() if v[0] == "delta"}
@@ -194,7 +205,7 @@ class FloristAggregator(Aggregator):
             for i, path in enumerate(paths):
                 sl = slice(i * L, (i + 1) * L)
                 device[path] = (Bg[sl], Ag[sl], sp[sl], pr[sl])
-        return self._materialize(device)
+        return device
 
     def _finalize_loop(self) -> AggResult:
         """Legacy per-(leaf, layer) eager loop — kept verbatim as the

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.common import telemetry
 from repro.common.pjit_utils import shard_map as _shard_map
 
 from repro.core.aggregators import AggResult, register_aggregator, set_path
@@ -151,11 +152,12 @@ class ShardedFloristAggregator(FloristAggregator):
                 self.mesh, tau=self.tau, svd_method=self.svd_method,
                 max_rank=self.max_rank)
         device: Dict[Tuple, Tuple] = {}
-        for path, inter in self._settle().items():
-            if inter[0] == "stack":
-                device[path] = self._fn_cache["fn"](inter[1], inter[2])
-            else:
-                device[path] = self._fn_cache["delta"](inter[1])
+        with telemetry.span("finalize.core"):
+            for path, inter in self._settle().items():
+                if inter[0] == "stack":
+                    device[path] = self._fn_cache["fn"](inter[1], inter[2])
+                else:
+                    device[path] = self._fn_cache["delta"](inter[1])
         # _materialize does the single device→host transfer + exact
         # truncation of the zero-padded columns
         return self._materialize(device)

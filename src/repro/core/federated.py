@@ -41,13 +41,14 @@ import dataclasses
 import functools
 import os
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint import io as ckpt_io
+from repro.common import telemetry
 from repro.common.config import FedConfig, LoRAConfig, ModelConfig, OptimConfig
 from repro.core.aggregators import (AggResult, Aggregator, accepted_config,
                                     make_aggregator)
@@ -90,7 +91,7 @@ class RoundRecord:
     global_rank_total: int
     upload_bytes: int = 0        # measured serialized uplink (all clients)
     download_bytes: int = 0      # measured serialized downlink (all clients)
-    wall_secs: float = 0.0       # wall-clock of the whole round
+    wall_secs: float = 0.0       # wall-clock of the whole round (its span)
     # -- fault-tolerance counters (PR 10) -----------------------------------
     retries: int = 0             # uplink re-sends after verification failure
     dead_clients: int = 0        # dropped uploads + retry-exhausted clients
@@ -197,7 +198,16 @@ class FederatedTrainer:
 
     # -- main loop ------------------------------------------------------------
     def run_round(self, rnd: int) -> RoundRecord:
-        t0 = time.perf_counter()
+        """One round; its wall time is the ``round`` span's."""
+        with telemetry.span("round", round=rnd) as sp:
+            rec = self._round(rnd)
+        rec.wall_secs = sp.seconds
+        self._pending_resumes = 0
+        self.history.append(rec)
+        self._maybe_crash(rnd, "post_round")
+        return rec
+
+    def _round(self, rnd: int) -> RoundRecord:
         self._maybe_crash(rnd, "begin")
         clock = self.transport.clock
         sim0 = clock.now if clock is not None else 0.0
@@ -253,7 +263,7 @@ class FederatedTrainer:
         gstats = self.gate.finish()
         tstats = self.transport.reset_stats()
         if not gstats.quorum_met or self.aggregator.num_clients == 0:
-            return self._degraded_round(rnd, t0, sim0, gstats, tstats,
+            return self._degraded_round(rnd, sim0, gstats, tstats,
                                         upload_bytes, dropped)
         agg = self.aggregator.finalize()
         dims = self.aggregator.dims
@@ -273,21 +283,23 @@ class FederatedTrainer:
             # so the merge consumes the decoded wire tensors, codec included
             if bcast is not None:
                 agg.global_adapters = bcast
-            self.params = merge_lora(self.params, agg.global_adapters)
+            with telemetry.span("merge"):
+                self.params = merge_lora(self.params, agg.global_adapters)
             eval_params = self.params
         else:
             # broadcast methods: the server evals its exact aggregate;
             # clients resume from the decoded broadcast
-            eval_params = merge_lora(self.params, agg.global_adapters)
+            with telemetry.span("merge"):
+                eval_params = merge_lora(self.params, agg.global_adapters)
             if bcast is not None:
                 agg.global_adapters = bcast
         self.global_state = agg
 
-        m = self._eval(eval_params, None, self.eval_batch)
-        rec = RoundRecord(
+        loss, acc = self._evaluate(eval_params)
+        return RoundRecord(
             round=rnd,
-            eval_loss=float(m["loss"]),
-            eval_acc=float(m["accuracy"]),
+            eval_loss=loss,
+            eval_acc=acc,
             upload_params=up,
             download_params=down,
             download_rank=agg.total_download_rank()
@@ -295,7 +307,6 @@ class FederatedTrainer:
             global_rank_total=agg.total_download_rank(),
             upload_bytes=upload_bytes,
             download_bytes=download_bytes,
-            wall_secs=time.perf_counter() - t0,
             retries=tstats.retries,
             dead_clients=tstats.dead_clients + dropped,
             rejected=gstats.rejected,
@@ -304,14 +315,15 @@ class FederatedTrainer:
             resumes=self._pending_resumes,
             sim_secs=(clock.now - sim0) if clock is not None else 0.0,
         )
-        self._pending_resumes = 0
-        self.history.append(rec)
-        self._maybe_crash(rnd, "post_round")
-        return rec
 
-    def _degraded_round(self, rnd: int, t0: float, sim0: float, gstats,
-                        tstats, upload_bytes: int,
-                        dropped: int = 0) -> RoundRecord:
+    def _evaluate(self, params) -> Tuple[float, float]:
+        """Eval loss and accuracy of ``params`` on the eval rows."""
+        with telemetry.span("eval"):
+            m = self._eval(params, None, self.eval_batch)
+            return float(m["loss"]), float(m["accuracy"])
+
+    def _degraded_round(self, rnd: int, sim0: float, gstats, tstats,
+                        upload_bytes: int, dropped: int = 0) -> RoundRecord:
         """Quorum failure: too few accepted updates to trust a fold.  The
         round degrades gracefully — the previous global state is kept (the
         half-filled accumulator is never finalized), clients will resume
@@ -320,15 +332,16 @@ class FederatedTrainer:
         gs = self.global_state
         if gs is not None and gs.global_adapters is not None \
                 and not gs.merge_into_base:
-            eval_params = merge_lora(self.params, gs.global_adapters)
+            with telemetry.span("merge"):
+                eval_params = merge_lora(self.params, gs.global_adapters)
         else:
             eval_params = self.params
-        m = self._eval(eval_params, None, self.eval_batch)
+        loss, acc = self._evaluate(eval_params)
         clock = self.transport.clock
-        rec = RoundRecord(
+        return RoundRecord(
             round=rnd,
-            eval_loss=float(m["loss"]),
-            eval_acc=float(m["accuracy"]),
+            eval_loss=loss,
+            eval_acc=acc,
             upload_params=self.aggregator.round_upload_params,
             download_params=0,
             download_rank=0.0,
@@ -336,7 +349,6 @@ class FederatedTrainer:
                                if gs is not None else 0),
             upload_bytes=upload_bytes,
             download_bytes=0,
-            wall_secs=time.perf_counter() - t0,
             retries=tstats.retries,
             dead_clients=tstats.dead_clients + dropped,
             rejected=gstats.rejected,
@@ -345,10 +357,6 @@ class FederatedTrainer:
             resumes=self._pending_resumes,
             sim_secs=(clock.now - sim0) if clock is not None else 0.0,
         )
-        self._pending_resumes = 0
-        self.history.append(rec)
-        self._maybe_crash(rnd, "post_round")
-        return rec
 
     # -- checkpoint / resume ---------------------------------------------------
     def state_dict(self, next_round: int) -> Dict[str, Any]:
@@ -418,14 +426,18 @@ class FederatedTrainer:
             start = self.restore_checkpoint(checkpoint)
         every = checkpoint_every or (1 if checkpoint else 0)
         for rnd in range(start, num_rounds or self.fed.num_rounds):
+            lo = time.perf_counter()
             rec = self.run_round(rnd)
             if verbose:
+                compiled = sum(n for n, _ in
+                               telemetry.compiles(lo, time.perf_counter())
+                               .values())
                 print(f"[{self.aggregator.name:9s}] round {rnd:3d} "
                       f"loss={rec.eval_loss:.4f} acc={rec.eval_acc:.3f} "
                       f"down_rank={rec.download_rank:.0f} "
                       f"up={rec.upload_bytes / 2**20:.2f}MB "
                       f"down={rec.download_bytes / 2**20:.2f}MB "
-                      f"{rec.wall_secs:.2f}s")
+                      f"{rec.wall_secs:.2f}s compiles={compiled}")
             if checkpoint and every and (rnd + 1) % every == 0:
                 self.save_checkpoint(checkpoint, rnd + 1)
         return self.history

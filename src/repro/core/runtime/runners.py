@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.common import telemetry
 from repro.optim.adamw import adamw_init
 from repro.train.step import make_train_step
 
@@ -99,7 +100,9 @@ def _init_getter(ctx):
         if task.init_adapters is not None:
             return task.init_adapters
         if task.rank not in cache:
-            cache[task.rank] = ctx._client_init(task.client_id, task.rank)
+            with telemetry.span("client.init", rank=task.rank):
+                cache[task.rank] = ctx._client_init(task.client_id,
+                                                    task.rank)
         return cache[task.rank]
 
     return get
@@ -134,12 +137,15 @@ class SequentialRunner(ClientRunner):
         task_init = _init_getter(ctx)
         for task in plan.tasks:
             init_adapters = task_init(task)
-            adapters = init_adapters
-            opt_state = adamw_init(adapters)
-            for batch in _batch_schedule(ctx, plan.round, task):
-                jb = {kk: jnp.asarray(v) for kk, v in batch.items()}
-                adapters, opt_state, _ = step(ctx.params, adapters,
-                                              opt_state, jb)
+            with telemetry.span("client.batches", client=task.client_id):
+                batches = [{kk: jnp.asarray(v) for kk, v in batch.items()}
+                           for batch in _batch_schedule(ctx, plan.round, task)]
+            with telemetry.span("client.train", client=task.client_id):
+                adapters = init_adapters
+                opt_state = adamw_init(adapters)
+                for jb in batches:
+                    adapters, opt_state, _ = step(ctx.params, adapters,
+                                                  opt_state, jb)
             deliver(task, adapters, init_adapters)
 
 
@@ -211,16 +217,15 @@ def _group_cohorts(plan) -> Dict[Tuple[int, int], List]:
     return cohorts
 
 
-def _stack_cohort(ctx, rnd: int, tasks: List, task_init, pad_c: int):
+def _stack_cohort(ctx, rnd: int, tasks: List, inits: List, pad_c: int):
     """Host-side prep for one cohort block: replay the sequential batch
     draws, zero-pad ragged batch sizes (padded rows carry ``loss_mask = 0``
     and contribute nothing to loss, gradient, or metric denominators),
     stack inits/batches along a new client axis, and pad the client axis to
     ``pad_c`` with inert replicas (zero mask ⇒ zero gradients).
 
-    Returns ``(stacked_adapters, {"tokens", "loss_mask"}, inits)`` with
-    ``inits`` the unpadded per-task init trees (deliver needs them for the
-    DP stage)."""
+    ``inits`` are the tasks' own init trees.  Returns ``(stacked_adapters,
+    {"tokens", "loss_mask"})`` with the batches on the device."""
     steps = tasks[0].steps
     scheds = [_batch_schedule(ctx, rnd, t) for t in tasks]
     seq_len = scheds[0][0]["tokens"].shape[1]
@@ -231,10 +236,10 @@ def _stack_cohort(ctx, rnd: int, tasks: List, task_init, pad_c: int):
         for si, b in enumerate(sched):
             toks[ci, si, : b["tokens"].shape[0]] = b["tokens"]
             mask[ci, si, : b["tokens"].shape[0]] = b["loss_mask"]
-    inits = [task_init(t) for t in tasks]
     padded = inits + [inits[0]] * (pad_c - len(tasks))
     stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *padded)
-    return stacked, {"tokens": toks, "loss_mask": mask}, inits
+    return stacked, {"tokens": jnp.asarray(toks),
+                     "loss_mask": jnp.asarray(mask)}
 
 
 @register_runner("cohort")
@@ -281,26 +286,27 @@ class CohortRunner(ClientRunner):
         params = self._params(ctx)
         order = {id(t): i for i, t in enumerate(plan.tasks)}
         buffered: Dict[int, Tuple] = {}
-        for _, tasks in _group_cohorts(plan).items():
-            for block in self._blocks(tasks):
-                pad_c = self._pad(len(block), ctx)
-                stacked, batch, inits = _stack_cohort(
-                    ctx, plan.round, block, task_init, pad_c)
-                self.peak_live_clients = max(self.peak_live_clients, pad_c)
-                out = train(params, stacked,
-                            {"tokens": jnp.asarray(batch["tokens"]),
-                             "loss_mask": jnp.asarray(batch["loss_mask"])})
+        blocks = (block for tasks in _group_cohorts(plan).values()
+                  for block in self._blocks(tasks))
+        for b, block in enumerate(blocks):
+            pad_c = self._pad(len(block), ctx)
+            inits = [task_init(t) for t in block]
+            with telemetry.span("client.batches", block=b):
+                stacked, batch = _stack_cohort(ctx, plan.round, block,
+                                               inits, pad_c)
+            self.peak_live_clients = max(self.peak_live_clients, pad_c)
+            with telemetry.span("client.train", block=b):
+                out = train(params, stacked, batch)
                 # ONE device→host transfer for the whole block; per-client
                 # unstacking is then free numpy views (eager per-leaf
                 # device slicing would cost a dispatch per (client, leaf))
                 host_out = jax.device_get(out)
-                for ci, task in enumerate(block):
-                    adapters = jax.tree.map(lambda x: x[ci], host_out)
-                    if self.stream:
-                        deliver(task, adapters, inits[ci])
-                    else:
-                        buffered[order[id(task)]] = (task, adapters,
-                                                     inits[ci])
+            for ci, task in enumerate(block):
+                adapters = jax.tree.map(lambda x: x[ci], host_out)
+                if self.stream:
+                    deliver(task, adapters, inits[ci])
+                else:
+                    buffered[order[id(task)]] = (task, adapters, inits[ci])
         for i in sorted(buffered):
             deliver(*buffered[i])
 

@@ -57,6 +57,7 @@ import jax
 import ml_dtypes
 import numpy as np
 
+from repro.common import telemetry
 from repro.core.aggregators.base import (adapter_leaf_paths,
                                          default_wire_arrays, get_path,
                                          set_path)
@@ -357,11 +358,9 @@ def _check_ragged(dec: np.ndarray, layer_shape: Tuple[int, ...], axis: int,
 @dataclasses.dataclass
 class TransportStats:
     """Per-round uplink reliability counters (reset by the trainer)."""
-    attempts: int = 0
     retries: int = 0
     crc_failures: int = 0
     dead_clients: int = 0
-    backoff_secs: float = 0.0
 
 
 #: rng stream tag for retry-backoff jitter
@@ -442,6 +441,15 @@ class Transport:
         The DP stage runs exactly once, before the first pack — a retry
         re-encodes the already-privatized tree, never re-clips/re-noises.
         """
+        with telemetry.span("wire.up", client=client_id) as sp:
+            decoded, nbytes = self._uplink(adapters, aggregator,
+                                           init_adapters, rnd, client_id)
+            sp.set(bytes=nbytes)
+        return decoded, nbytes
+
+    def _uplink(self, adapters: Dict, aggregator,
+                init_adapters: Optional[Dict], rnd: int, client_id: int
+                ) -> Tuple[Dict, int]:
         wire = _wire_fn(aggregator)
         adapters = self._dp_stage(adapters, init_adapters, rnd, client_id)
         total_bytes, last_err = 0, None
@@ -452,7 +460,6 @@ class Transport:
                     rnd, client_id, attempt):
                 payload = self.fault_plan.corrupt_payload(
                     payload, rnd, client_id, attempt)
-            self.stats.attempts += 1
             total_bytes += payload.num_bytes
             try:
                 decoded = payload.unpack_into(adapters, self.codec,
@@ -468,7 +475,6 @@ class Transport:
                         [_JITTER_TAG, rnd, client_id, attempt]).random())
                     delay = (self.backoff_base * 2 ** attempt
                              * (1.0 + self.backoff_jitter * u))
-                    self.stats.backoff_secs += delay
                     if self.clock is not None:
                         self.clock.advance(delay)
         self.stats.dead_clients += 1
@@ -483,7 +489,14 @@ class Transport:
         (FlexLoRA) ship each tailored tree once.  Returns the decoded
         global tree (what clients resume from) and total downlink bytes.
         """
-        wire = _wire_fn(aggregator)
+        with telemetry.span("wire.down") as sp:
+            decoded, nbytes = self._downlink(agg, _wire_fn(aggregator),
+                                             num_receivers)
+            sp.set(bytes=nbytes)
+        return decoded, nbytes
+
+    def _downlink(self, agg, wire, num_receivers: int
+                  ) -> Tuple[Optional[Dict], int]:
         if agg.per_client is not None:
             nbytes = sum(
                 AdapterPayload.pack(t, self.codec, wire).num_bytes

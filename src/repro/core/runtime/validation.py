@@ -50,6 +50,7 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
+from repro.common import telemetry
 from repro.core.aggregators.base import (adapter_leaf_paths, get_path,
                                          leaf_dims)
 
@@ -128,6 +129,12 @@ class ValidationGate:
         """Screen one arriving update; fold it (``screen``/``off``) or
         hold it for the round verdict (``full``).  Returns False iff the
         update was rejected outright."""
+        client = getattr(task, "client_id", None)
+        with telemetry.span("gate", **({} if client is None
+                                       else {"client": client})):
+            return self._submit(task, update, weight, rank, init_adapters)
+
+    def _submit(self, task, update, weight, rank, init_adapters) -> bool:
         self.stats.submitted += 1
         if self.mode == "off":
             self._agg.add_client(update, weight, rank=rank)

@@ -220,9 +220,13 @@ def florist_core_padded(B_stack: jnp.ndarray, A_stack: jnp.ndarray, tau,
 
 @functools.lru_cache(maxsize=None)
 def _batched_core_fn(tau, svd_method: str, max_rank: int):
-    fn = functools.partial(florist_core_padded, tau=tau,
-                           svd_method=svd_method, max_rank=max_rank)
-    return jax.jit(jax.vmap(fn))
+    core = functools.partial(florist_core_padded, tau=tau,
+                             svd_method=svd_method, max_rank=max_rank)
+
+    def florist_core(B_stacks, A_stacks):
+        return jax.vmap(core)(B_stacks, A_stacks)
+
+    return jax.jit(florist_core)         # executable ``jit_florist_core``
 
 
 def florist_core_batched(B_stacks: jnp.ndarray, A_stacks: jnp.ndarray, tau,
@@ -272,9 +276,13 @@ def florist_core_delta_padded(M: jnp.ndarray, tau, svd_method: str = "svd",
 
 @functools.lru_cache(maxsize=None)
 def _batched_delta_fn(tau, svd_method: str, max_rank: int):
-    fn = functools.partial(florist_core_delta_padded, tau=tau,
-                           svd_method=svd_method, max_rank=max_rank)
-    return jax.jit(jax.vmap(fn))
+    core = functools.partial(florist_core_delta_padded, tau=tau,
+                             svd_method=svd_method, max_rank=max_rank)
+
+    def florist_core_delta(Ms):
+        return jax.vmap(core)(Ms)
+
+    return jax.jit(florist_core_delta)   # ``jit_florist_core_delta``
 
 
 def florist_core_delta_batched(Ms: jnp.ndarray, tau,
