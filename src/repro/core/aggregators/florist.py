@@ -28,9 +28,9 @@ class FloristAggregator(Aggregator):
     to a per-leaf pending list and, every ``flush_every`` arrivals, the
     pending blocks are compacted on device —
 
-    * **stacked mode** (small rounds): the pending blocks are concatenated
-      into one (L, m, Σr) / (L, Σr, n) pair, the exact intermediate the
-      paper's pipeline thin-SVDs at finalize;
+    * **stacked mode** (small rounds): the pending blocks are concatenated,
+      widest first, into one (L, m, Σr) / (L, Σr, n) pair, the exact
+      intermediate the paper's pipeline thin-SVDs at finalize;
     * **delta mode** (``stream="delta"``, or ``"auto"`` once the stack
       width Σ r_k would exceed ``min(m, n)``): the pending blocks are
       contracted into a running dense update ``M += B_pend A_pend`` —
@@ -82,7 +82,8 @@ class FloristAggregator(Aggregator):
         for path in adapter_leaf_paths(update):
             Bk, Ak = fold_scale(get_path(update, path))
             acc = self._state.setdefault(
-                path, {"stacked": Ak.ndim == 3, "A": [], "B": [], "M": None})
+                path, {"stacked": Ak.ndim == 3, "A": [], "B": [], "M": None,
+                       "widths": ()})
             acc["B"].append(Bk)
             acc["A"].append(weight * Ak)
             self.peak_pending_blocks = max(self.peak_pending_blocks,
@@ -105,10 +106,19 @@ class FloristAggregator(Aggregator):
         bounding the pending list at ``flush_every`` entries."""
         if not acc["B"]:
             return
-        B = acc["B"][0] if len(acc["B"]) == 1 \
-            else jnp.concatenate(acc["B"], axis=-1)
-        A = acc["A"][0] if len(acc["A"]) == 1 \
-            else jnp.concatenate(acc["A"], axis=-2)
+        # widest block first, arrival order among equal widths (a stable
+        # sort), one permutation for the B columns and the A rows: ΔW =
+        # Σ B_k A_k is unchanged, and the concatenation's shape signature
+        # follows from the rank multiset alone, so the eager concatenate
+        # compiles once per multiset rather than once per arrival order
+        order = sorted(range(len(acc["B"])),
+                       key=lambda i: -acc["B"][i].shape[-1])
+        Bs = [acc["B"][i] for i in order]
+        As = [acc["A"][i] for i in order]
+        if len(Bs) > 1 or not acc["widths"]:
+            acc["widths"] = tuple(b.shape[-1] for b in Bs)
+        B = Bs[0] if len(Bs) == 1 else jnp.concatenate(Bs, axis=-1)
+        A = As[0] if len(As) == 1 else jnp.concatenate(As, axis=-2)
         if self._delta_mode(acc):
             d = B @ A                       # (L, m, n) / (m, n): batched matmul
             acc["M"] = d if acc["M"] is None else acc["M"] + d
@@ -175,8 +185,9 @@ class FloristAggregator(Aggregator):
     def _finalize(self) -> AggResult:
         if self.pipeline == "loop":
             return self._finalize_loop()
-        with telemetry.span("finalize.core"):
+        with telemetry.span("finalize.core") as s:
             device = self._dispatch_cores()
+            s.set(stack_widths=next(iter(self._state.values()))["widths"])
         return self._materialize(device)
 
     def _dispatch_cores(self) -> Dict[Tuple, Tuple]:

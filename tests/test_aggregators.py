@@ -348,3 +348,72 @@ def test_sharded_florist_backend_matches_host_deltaw(rng):
         np.testing.assert_allclose(
             np.asarray(h["B"][l] @ h["A"][l]),
             np.asarray(s["B"][l] @ s["A"][l]), rtol=1e-3, atol=1e-3)
+
+
+class TestArrivalOrder:
+    """FLoRIST stacks client blocks in descending width, whatever order the
+    clients arrive in: the same ΔW, and one concatenation signature per rank
+    multiset, so a new arrival order compiles nothing in the finalize."""
+
+    RANKS = [4, 4, 8, 16, 64]
+    WEIGHTS = [0.3, 0.25, 0.2, 0.15, 0.1]
+
+    def _clients(self, seed=14):
+        rng = np.random.default_rng(seed)
+        trees = []
+        for r in self.RANKS:
+            t = _client_tree(rng, L=2, m=128, n=112, r=r)
+            t["blocks"][0]["attn"]["wk"] = _client_tree(
+                rng, L=2, m=32, n=112, r=r)["blocks"][0]["attn"]["wq"]
+            trees.append(t)
+        return trees
+
+    def _run(self, agg, trees, order):
+        agg.begin_round()
+        for i in order:
+            agg.add_client(trees[i], self.WEIGHTS[i], rank=self.RANKS[i])
+        return agg.finalize()
+
+    @pytest.mark.parametrize("method,kw", [
+        ("florist", {"stream": "stacked"}),
+        ("florist", {"stream": "delta"}),
+        ("florist_sharded", {"svd_method": "svd"}),
+    ], ids=["stacked", "delta", "sharded"])
+    def test_result_invariant_to_arrival_order(self, method, kw):
+        from repro.core.distributed import ShardedFloristAggregator  # noqa: F401 (registers)
+
+        trees = self._clients()
+        a = self._run(make_aggregator(method, tau=0.9, **kw), trees,
+                      [0, 1, 2, 3, 4])
+        b = self._run(make_aggregator(method, tau=0.9, **kw), trees,
+                      [2, 4, 0, 3, 1])
+        assert a.ranks == b.ranks
+        for p in a.spectra:
+            for s1, s2 in zip(a.spectra[p], b.spectra[p]):
+                np.testing.assert_allclose(s1, s2, rtol=1e-4, atol=1e-4)
+        for p in adapter_leaf_paths(a.global_adapters):
+            la, lb = get_path(a.global_adapters, p), get_path(b.global_adapters, p)
+            for layer in range(2):
+                np.testing.assert_allclose(
+                    np.asarray(la["B"][layer] @ la["A"][layer]),
+                    np.asarray(lb["B"][layer] @ lb["A"][layer]),
+                    rtol=1e-4, atol=1e-4)
+
+    def test_new_arrival_order_compiles_nothing_in_finalize(self):
+        import time
+
+        from repro.common import telemetry
+
+        trees = self._clients()
+        agg = make_aggregator("florist", tau=0.9)
+        lo = time.perf_counter()
+        self._run(agg, trees, [3, 0, 4, 2, 1])
+        mid = time.perf_counter()
+        self._run(agg, trees, [1, 4, 2, 0, 3])
+        # eager ops compile on the CPU too: every one in the second
+        # finalize's core must come from the cache
+        assert telemetry.compiles(mid).get("finalize.core", (0, 0.0))[0] == 0
+        cores = telemetry.records("finalize.core", lo)
+        assert len(cores) == 2
+        w1, w2 = (s.attrs["stack_widths"] for s in cores)
+        assert w1 == w2 == tuple(sorted(self.RANKS, reverse=True))
