@@ -1,23 +1,23 @@
-"""A configuration file to the program's model config, and the base weights
-and LoRA factors made by the benchmark from the seed.
+"""A configuration file to its model family, the base weights and LoRA
+init made by the benchmark from the seed, and the walk over the program's
+adapter trees.
 
-Weights are made on the device in one jitted call, in the type they are
-served in (bf16), in the program's parameter layout: ``embed``,
-``final_norm``, ``lm_head`` (untied only) and one stacked segment of blocks
-``{ln1, attn: {wq, wk, wv, wo, bq, bk, bv}, ln2, mlp: {w_gate, w_up,
-w_down}}``.  The plain reference reads the same arrays, so the reference
-never takes weights the program made.
+The family is ``bench/families/<model_type>.py`` (see
+``bench/families/__init__.py``); it knows the block layout, and this module
+knows none.  Weights are made on the device in one jitted call, in the type
+they are served in; the plain reference reads the same arrays, so the
+reference never takes weights the program made.
 """
 from __future__ import annotations
 
 import functools
+import importlib
 import json
 import os
-from typing import Dict
+from typing import Dict, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -27,23 +27,14 @@ def load_config(name: str) -> dict:
         return json.load(f)
 
 
-def program_config(c: dict):
-    """The program's ``ModelConfig`` for a Qwen2-family configuration file
-    (published ``config.json`` keys)."""
-    from repro.common.config import ModelConfig
-
-    return ModelConfig(
-        name=c["name"], family="dense",
-        num_layers=c["num_hidden_layers"], d_model=c["hidden_size"],
-        num_heads=c["num_attention_heads"],
-        num_kv_heads=c["num_key_value_heads"],
-        head_dim=c["hidden_size"] // c["num_attention_heads"],
-        d_ff=c["intermediate_size"], vocab_size=c["vocab_size"],
-        qkv_bias=bool(c.get("qkv_bias", True)),
-        tie_embeddings=bool(c.get("tie_word_embeddings", False)),
-        rope_theta=float(c["rope_theta"]), norm_eps=float(c["rms_norm_eps"]),
-        dtype={"bfloat16": "bfloat16", "float32": "float32"}[c["torch_dtype"]],
-        source=c["source"])
+def family(c: dict):
+    """The family module named by the configuration's ``model_type``."""
+    name = str(c.get("model_type", ""))
+    path = os.path.join(HERE, "bench", "families", name + ".py")
+    if not name.isidentifier() or not os.path.isfile(path):
+        raise ValueError(f"model_type {name!r} of {c.get('name')!r} has no "
+                         f"family module: {path} does not exist")
+    return importlib.import_module("bench.families." + name)
 
 
 def seed_key(seed: int) -> jax.Array:
@@ -52,45 +43,15 @@ def seed_key(seed: int) -> jax.Array:
     return jax.random.fold_in(k, (seed >> 32) & 0xFFFFFFFF)
 
 
-def _weights(c_items, key):
-    c = dict(c_items)
-    d, V, L = c["hidden_size"], c["vocab_size"], c["num_hidden_layers"]
-    H, K = c["num_attention_heads"], c["num_key_value_heads"]
-    hd, ff = d // H, c["intermediate_size"]
-    dt = jnp.dtype(c["torch_dtype"])
-    ks = iter(jax.random.split(key, 16))
-
-    def mat(shape, fan_in):
-        return (jax.random.normal(next(ks), shape, jnp.float32)
-                / np.sqrt(fan_in)).astype(dt)
-
-    def vec(shape, mean, std):
-        return (mean + std * jax.random.normal(next(ks), shape, jnp.float32)
-                ).astype(dt)
-
-    attn = {"wq": mat((L, d, H * hd), d), "wk": mat((L, d, K * hd), d),
-            "wv": mat((L, d, K * hd), d), "wo": mat((L, H * hd, d), H * hd),
-            "bq": vec((L, H * hd), 0.0, 0.1), "bk": vec((L, K * hd), 0.0, 0.1),
-            "bv": vec((L, K * hd), 0.0, 0.1)}
-    blocks = {"ln1": vec((L, d), 1.0, 0.1), "attn": attn,
-              "ln2": vec((L, d), 1.0, 0.1),
-              "mlp": {"w_gate": mat((L, d, ff), d), "w_up": mat((L, d, ff), d),
-                      "w_down": mat((L, ff, d), ff)}}
-    w = {"embed": vec((V, d), 0.0, 0.02), "final_norm": vec((d,), 1.0, 0.1),
-         "blocks": (blocks,)}
-    if not c.get("tie_word_embeddings"):
-        w["lm_head"] = mat((d, V), d)
-    return w
+def config_items(c: dict):
+    return tuple(sorted((k, v) for k, v in c.items()
+                        if isinstance(v, (int, float, str, bool))))
 
 
 @functools.lru_cache(maxsize=None)
 def _weights_fn(c_items):
-    return jax.jit(functools.partial(_weights, c_items))
-
-
-def config_items(c: dict):
-    return tuple(sorted((k, v) for k, v in c.items()
-                        if isinstance(v, (int, float, str, bool))))
+    c = dict(c_items)
+    return jax.jit(functools.partial(family(c).weights, c))
 
 
 def make_weights(c: dict, seed: int) -> Dict:
@@ -98,22 +59,72 @@ def make_weights(c: dict, seed: int) -> Dict:
     return _weights_fn(config_items(c))(jax.random.fold_in(seed_key(seed), 1))
 
 
-def target_dims(c: dict, target: str):
-    d = c["hidden_size"]
-    H, K = c["num_attention_heads"], c["num_key_value_heads"]
-    hd = d // H
-    return {"wq": (d, H * hd), "wk": (d, K * hd), "wv": (d, K * hd),
-            "wo": (H * hd, d)}[target]
+# -- adapter trees ---------------------------------------------------------------
 
 
-def lora_tree(c: dict, factors: Dict) -> Dict:
-    """The program's adapter tree from {target: (A, B, scale)}: the leaves
-    mirror the base weights' path, with the block segment keyed 0."""
-    return {"blocks": {0: {"attn": {t: {"A": A, "B": B, "scale": s}
-                                    for t, (A, B, s) in factors.items()}}}}
+def adapter_leaves(tree: Dict) -> Dict[Tuple, Tuple]:
+    """{path: (A, B, scale)} of every adapter in a program adapter tree (a
+    node with an ``A``), in the tree's order; the path is the keys from the
+    root, segment indices included."""
+    out = {}
+
+    def walk(node, path):
+        if "A" in node:
+            out[path] = (node["A"], node["B"], node["scale"])
+            return
+        for k, v in node.items():
+            walk(v, path + (k,))
+
+    walk(tree, ())
+    return out
 
 
-def lora_factors(tree: Dict) -> Dict:
-    """Inverse of :func:`lora_tree`."""
-    return {t: (v["A"], v["B"], v["scale"])
-            for t, v in tree["blocks"][0]["attn"].items()}
+def adapter_tree(leaves: Dict[Tuple, Tuple]) -> Dict:
+    """Inverse of :func:`adapter_leaves`."""
+    tree: Dict = {}
+    for path, (A, B, s) in leaves.items():
+        node = tree
+        for k in path[:-1]:
+            node = node.setdefault(k, {})
+        node[path[-1]] = {"A": A, "B": B, "scale": s}
+    return tree
+
+
+def lora_init(weights: Dict, targets: Sequence[str], rank: int,
+              key: jax.Array) -> Dict:
+    """A LoRA init over every target projection of every segment of
+    ``weights["blocks"]`` (stacked leaves ``(L, d_in, d_out)``), as a program
+    adapter tree: A Gaussian (std 0.02), B zero, scale 1.  One key is split
+    over the leaves in order of segment, then of ``targets``."""
+    found = []
+    for s, seg in enumerate(weights["blocks"]):
+        paths = jax.tree_util.tree_flatten_with_path(seg)[0]
+        for t in targets:
+            for p, w in paths:
+                keys = tuple(getattr(k, "key", getattr(k, "idx", None))
+                             for k in p)
+                if keys[-1] == t and w.ndim == 3:
+                    found.append((("blocks", s) + keys, w.shape))
+    ks = jax.random.split(key, len(found))
+    return adapter_tree({
+        path: (jax.random.normal(k, (L, rank, din), jnp.float32) * 0.02,
+               jnp.zeros((L, dout, rank), jnp.float32),
+               jnp.ones((L,), jnp.float32))
+        for (path, (L, din, dout)), k in zip(found, ks)})
+
+
+def lora_factors(fam, tree: Dict) -> Dict:
+    """{key: (A, B, scale)} of a program adapter tree, keyed by the family's
+    ``lora_key``."""
+    out = {}
+    for path, f in adapter_leaves(tree).items():
+        key = fam.lora_key(path)
+        if key in out:
+            raise ValueError(f"adapters at two paths have the key {key!r}")
+        out[key] = f
+    return out
+
+
+def lora_tree(fam, factors: Dict) -> Dict:
+    """Inverse of :func:`lora_factors`."""
+    return adapter_tree({fam.lora_path(k): f for k, f in factors.items()})

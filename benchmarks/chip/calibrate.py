@@ -60,7 +60,8 @@ def main(argv=None) -> int:
     dev = jax.devices()[0]
     for kind, seed, fault in jobs:
         t0 = time.perf_counter()
-        cell_run = driver.setup(c, t, seed, sp.Spans(), 0.0, fault=fault)
+        cell_run = driver.setup(c, t, seed, sp.Spans(), 0.0,
+                                jax.devices()[:cell["chips"]], fault=fault)
         t1 = time.perf_counter()
         observed = (cell_run.control_observed() if kind == "control"
                     else cell_run.program_observed())

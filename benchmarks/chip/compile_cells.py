@@ -3,8 +3,10 @@ sizes, without a chip, and print what the compiler says of their memory.
 
     JAX_PLATFORMS=cpu python3 benchmarks/chip/compile_cells.py [--workload W]
 
-Round cells: the client train step at each rank of the mix, the eval step,
-and the FLoRIST finalize core for the round's stack width.  Each is lowered from shapes alone (the
+Round cells: the client train step at each rank of the mix (the program
+the sequential runner steps through; a cohort runner's vmapped program is
+not compiled here), the eval step, and the FLoRIST finalize core for the
+round's stack width.  Each is lowered from shapes alone (the
 weights are never made) as the program builds it with its defaults, and
 compiled for one chip of a described ``v5e:2x2``; its ``memory_analysis``
 is printed beside the weights' bytes, to size batch and depth so that a
@@ -49,7 +51,7 @@ def main(argv=None) -> int:
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    from bench import flops, model, traffic
+    from bench import model, traffic
     from repro.common.config import OptimConfig
     from repro.optim.adamw import adamw_init
     from repro.train.loss import bounded_loss_chunk
@@ -73,23 +75,25 @@ def main(argv=None) -> int:
             continue
         c = model.load_config(cell["config"])
         t = traffic.load_traffic(cell["traffic"])
-        mc = model.program_config(c)
+        fam = model.family(c)
+        mc = fam.program_config(c)
         w = sds(jax.eval_shape(lambda: model.make_weights(c, 0)))
-        wb = flops.Dims.from_config(c).weight_bytes
+        wb = fam.Dims.from_config(c).weight_bytes
         print(f"== {cell['name']}", flush=True)
         if t["driver"] == "round":
             targets = tuple(t["targets"])
+
+            def adapters(rank):
+                return sds(jax.eval_shape(lambda: model.lora_init(
+                    w, targets, rank, jax.random.PRNGKey(0))))
+
             optim = OptimConfig(**{k: tuple(v) if k == "betas" else v
                                    for k, v in t["optim"].items()})
             step = jax.jit(make_train_step(mc, optim, remat=False, loss_chunk=64))
             batch = sds({"tokens": jnp.zeros((t["batch"], t["seq_len"]), jnp.int32),
                          "loss_mask": jnp.zeros((t["batch"], t["seq_len"]), jnp.float32)})
             for r in sorted({r for r, _ in t["clients"]}):
-                L = c["num_hidden_layers"]
-                fac = {tg: (jnp.zeros((L, r, model.target_dims(c, tg)[0]), jnp.float32),
-                            jnp.zeros((L, model.target_dims(c, tg)[1], r), jnp.float32),
-                            jnp.zeros((L,), jnp.float32)) for tg in targets}
-                ad = sds(jax.eval_shape(lambda: model.lora_tree(c, fac)))
+                ad = adapters(r)
                 opt = sds(jax.eval_shape(adamw_init, ad))
                 report(f"train_step rank {r}",
                        step.lower(w, ad, opt, batch).compile(), wb)
@@ -100,13 +104,16 @@ def main(argv=None) -> int:
             report("eval_step", ev.lower(w, None, eb).compile(), wb)
             from repro.core.svd import florist_core_batched
             width = sum(r * n for r, n in t["clients"])
-            L = c["num_hidden_layers"]
-            for m_, n_ in sorted({model.target_dims(c, tg)[::-1] for tg in targets}):
-                G = sum(1 for tg in targets if model.target_dims(c, tg)[::-1] == (m_, n_))
-                Bs = jax.ShapeDtypeStruct((G * L, m_, width), jnp.float32, sharding=one)
-                As = jax.ShapeDtypeStruct((G * L, width, n_), jnp.float32, sharding=one)
+            # (d_out, d_in) -> layers of every leaf of that shape
+            stacks = {}
+            for A, B, _ in model.adapter_leaves(adapters(1)).values():
+                mn = (B.shape[-2], A.shape[-1])
+                stacks[mn] = stacks.get(mn, 0) + A.shape[0]
+            for (m_, n_), GL in sorted(stacks.items()):
+                Bs = jax.ShapeDtypeStruct((GL, m_, width), jnp.float32, sharding=one)
+                As = jax.ShapeDtypeStruct((GL, width, n_), jnp.float32, sharding=one)
                 fn = jax.jit(lambda B, A: florist_core_batched(B, A, t["tau"], "svd", 0))
-                report(f"finalize core ({G * L}, {m_}, {width})",
+                report(f"finalize core ({GL}, {m_}, {width})",
                        fn.lower(Bs, As).compile(), 0)
     return 0
 

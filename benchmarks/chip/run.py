@@ -4,11 +4,15 @@
         --seconds <s> --trace <0|1>
 
 The cell (``BENCHMARK.json`` ``workloads``) names a configuration
-(``configs/<config>.json``) and a traffic mix (``traffic/<mix>.json``); the
-mix names its driver (``drivers/<driver>.py``), and each per-layer metric
-has its reader (``metrics/<metric>.py``).  Correctness limits are in
-``limits/<workload>.json``.  A cell is added by adding such files and
-entries; this file does not change.
+(``configs/<config>.json``, whose ``model_type`` names its family
+``bench/families/<model_type>.py`` and whose ``reference`` its plain
+reference ``references/<reference>.py``) and a traffic mix
+(``traffic/<mix>.json``); the mix names its driver
+(``drivers/<driver>.py``), and each per-layer metric has its reader
+(``metrics/<metric>.py``).  Correctness limits are in
+``limits/<workload>.json``, one for each reading the driver makes.  The
+driver gets the first ``chips`` devices.  A cell is added by adding such
+files and entries; this file does not change.
 
 The run: device check (a TPU with enough chips, else exit 3 with no
 result), the persistent compile cache, set-up by the driver (weights,
@@ -163,8 +167,15 @@ def run_cell(bench, cell, c, t, limits, args, devices, cache,
     driver = load_module("drivers", t["driver"])
     spans = sp.Spans()
     snap0 = log.snapshot()
-    run = driver.setup(c, t, args.seed, spans, args.seconds)
+    run = driver.setup(c, t, args.seed, spans, args.seconds, used)
     snap1 = log.snapshot()
+    made = set(run.readings_made)
+    if made != set(limits):
+        raise ValueError(
+            f"limits/{cell['name']}.json names {sorted(limits)}; this cell's "
+            f"runner makes {sorted(made)} (no limit for "
+            f"{sorted(made - set(limits))}, no reading for "
+            f"{sorted(set(limits) - made)})")
 
     trace_dir = tempfile.mkdtemp(prefix="bench_trace_") if args.trace else None
     if trace_dir:
@@ -205,7 +216,7 @@ def run_cell(bench, cell, c, t, limits, args, devices, cache,
         device["busy_s"] = summary.busy_s
         device["window_s"] = summary.window_s
         ctx = dict(run.layer_context(), trace=summary, spans=spans,
-                   window=(t0, t1), peaks=pk)
+                   window=(t0, t1), peaks=pk, chips=len(used))
         for m in layer:
             v = load_module("metrics", m["name"]).read(ctx)
             if v is not None:

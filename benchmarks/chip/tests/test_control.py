@@ -3,6 +3,7 @@ configuration's (fp8 for the bf16 model) put in the program's place must
 come out not correct under each cell's limits, while the program itself
 passes.  At a tiny size on the CPU; the chip readings at the cells' own
 sizes are in PERF.md."""
+import jax
 import pytest
 
 from bench import compare
@@ -17,7 +18,8 @@ def test_control_fails_the_limits(kind):
     name, traffic, seconds = CELLS[kind]
     t = data(traffic)
     driver = run.load_module("drivers", t["driver"])
-    cell = driver.setup(data("tiny"), t, 41, sp.Spans(), seconds)
+    cell = driver.setup(data("tiny"), t, 41, sp.Spans(), seconds,
+                        jax.devices()[:1])
     cell.release()
     limits = compare.load_limits(name)
     program = cell.readings(cell.program_observed())

@@ -9,6 +9,6 @@ from test_rehearsal import rehearse
 
 
 @pytest.mark.parametrize("fault", faults.ROUND_FAULTS)
-def test_round_fault_is_caught(fault, monkeypatch):
-    res = rehearse("round", seed=31, fault=fault, monkeypatch=monkeypatch)
+def test_round_fault_is_caught(fault):
+    res = rehearse("round", seed=31, fault=fault)
     assert res["correct"] is False, res["checks"]

@@ -6,13 +6,14 @@ import os
 import pytest
 
 from bench import flops
+from bench.families import qwen2
 
 CONFIGS = os.path.join(os.path.dirname(os.path.dirname(__file__)), "configs")
 
 
 def dims(name):
     with open(os.path.join(CONFIGS, name + ".json")) as f:
-        return flops.Dims.from_config(json.load(f))
+        return qwen2.Dims.from_config(json.load(f))
 
 
 def test_qwen2_0p5b_block_and_weights():
@@ -28,7 +29,7 @@ def test_qwen2_0p5b_block_and_weights():
 
 def test_qwen2_0p5b_train_step_by_hand():
     d = dims("qwen2-0.5b")
-    ops, nbytes = flops.train_step_work(d, rows=1, seq=4, rank=2,
+    ops, nbytes = qwen2.train_step_work(d, rows=1, seq=4, rank=2,
                                         targets=("wq", "wv"))
     blocks = 2 * 24 * 14_909_440 * 4               # 2 N per token
     head = 2 * 896 * 151_936 * 3                   # 3 predicted positions
@@ -44,7 +45,7 @@ def test_qwen2p5_14b_block_matches_published_split():
          "num_attention_heads": 40, "num_key_value_heads": 8,
          "intermediate_size": 13824, "vocab_size": 152064,
          "tie_word_embeddings": False}
-    d = flops.Dims.from_config(c)
+    d = qwen2.Dims.from_config(c)
     attn = 2 * 5120 * 5120 + 2 * 5120 * 1024       # 62.9 M
     mlp = 3 * 5120 * 13824                         # 212.3 M
     assert d.block_matmul_params == attn + mlp == 275_251_200
